@@ -74,6 +74,7 @@ import numpy as np
 
 from repro.configs.registry import get_config, smoke_config
 from repro.launch.costing import spec_decode_cost
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import ensure_host_devices, make_mesh, parse_mesh
 from repro.models.api import build_model
 from repro.serve import (GREEDY, OracleDrafter, Sampler, ServeEngine,
@@ -759,6 +760,7 @@ def main(argv=None):
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write the JSON record here (default: stdout)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if sum(map(bool, (args.paged, args.spec_decode, args.mesh,
                       args.slo, args.backends, args.replicas))) > 1:

@@ -186,11 +186,12 @@ def _pallas_stream_bytes(eqn, grid: int) -> float:
     n_in = getattr(gm, "num_inputs", len(mappings))
     total = 0.0
     for bm in list(mappings)[:n_in]:
-        aval = getattr(bm, "array_shape_dtype", None)
+        aval = getattr(bm, "array_aval", None)
         shape = getattr(bm, "block_shape", None)
         if aval is None or shape is None:
             continue
-        blk = _prod(int(s) for s in shape if s is not None)
+        # block dims are Blocked(block_size=n) entries; squeezed dims count 1
+        blk = _prod(int(getattr(s, "block_size", 1)) for s in shape)
         total += float(blk) * _itemsize(aval.dtype) * grid
     return total
 

@@ -57,9 +57,12 @@ PRNG_PRIMITIVES = {
 }
 
 #: cross-device collectives — checked for the ``model`` axis on ssm/hybrid
+#: (``*_invariant``: a psum / all_gather whose result no longer varies over
+#: the axis, as shard_map traces them)
 COLLECTIVE_PRIMITIVES = {
-    "psum", "psum2", "pmax", "pmin", "all_gather", "all_to_all",
-    "ppermute", "reduce_scatter", "pgather",
+    "psum", "psum2", "psum_invariant", "pmax", "pmin", "all_gather",
+    "all_gather_invariant", "all_to_all", "ppermute", "reduce_scatter",
+    "pgather",
 }
 
 #: the only files allowed to originate a bf16/f16 → f32 upcast on a serve

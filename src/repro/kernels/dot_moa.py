@@ -19,9 +19,11 @@ Variants:
     benchmarks/fig5_loa.py): the LOA fold costs ~6 VPU ops where the exact
     fold is a single hard add the MXU gives away for free.
 
-Block sizes default to MXU-aligned (multiples of 128 on the matmul dims);
-VMEM per step = (block_m·block_k + block_k·block_n + block_m·block_n)·4 B —
-512³ blocks ≈ 3 MiB, far under the 128 MiB VMEM.
+Block sizes default to MXU-aligned (multiples of 128 on the matmul dims).
+VMEM per step holds the double-buffered input tiles, their f32 up-casts and
+the f32 output block. The TPU v5e compiler gives a kernel a 16 MiB scoped
+VMEM limit by default: the serve default in bf16 (256 × 2048 × 256 blocks)
+compiles under it, while 512 × 4096 × 512 asks for 18 MiB and is refused.
 """
 
 from __future__ import annotations

@@ -19,6 +19,7 @@ import os
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "parse_mesh",
            "ensure_host_devices", "SINGLE_POD", "MULTI_POD"]
@@ -30,7 +31,7 @@ MULTI_POD = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...],
@@ -39,7 +40,15 @@ def make_mesh(shape: Tuple[int, ...],
     if axes is None:
         axes = ("pod", "data", "model")[-len(shape):] if len(shape) == 3 \
             else ("data", "model")[-len(shape):]
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the model code places arrays with
+    # ``with_sharding_constraint``, which refuses Explicit mesh axes
+    # (``jax.make_mesh``'s default)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def parse_mesh(spec: str) -> Tuple[int, ...]:

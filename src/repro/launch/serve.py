@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs.base import ShapeSpec
 from repro.configs.registry import get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import ensure_host_devices, make_mesh, parse_mesh
 from repro.models.api import build_model
 from repro.serve import (GREEDY, ReplicaSet, Sampler, ServeEngine, StepClock,
@@ -415,9 +416,13 @@ def main():
                     help="force greedy decode regardless of --temperature")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mesh:
         # before any backend touch: XLA locks device count at first init
         ensure_host_devices(parse_mesh(args.mesh))
+    dev = jax.devices()[0]
+    print(f"[serve] platform={dev.platform} device_kind={dev.device_kind} "
+          f"devices={jax.device_count()}")
     if args.replicas == -1:
         from repro.runtime import plan_replicas
         args.replicas = plan_replicas(jax.device_count())

@@ -21,11 +21,13 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from repro.layers.common import Params, dense_init
 from repro.layers.numerics import NEG_INF, kv_scale_zeros, online_softmax_init
 from repro.layers.rope import apply_rope
-from repro.parallel import constrain
+from repro.parallel import active_context, constrain
+from repro.parallel.sharding import _drop_indivisible, constraint_spec
 
 __all__ = [
     "init_attention", "attention_forward", "attention_decode",
@@ -589,15 +591,39 @@ def _paged_attention_fused(q, pool: Params, block_tables, start, *,
     ``compute_dtype`` is the dtype the gather path would materialize that
     view in; the kernel rounds its dequantized values through it so the
     two backends agree bit-for-bit on every attended KV entry.
+
+    Under an active multi-device mesh the call is partitioned by hand, since
+    GSPMD cannot split a Mosaic kernel: a ``shard_map`` gives each device
+    its slots (the ``batch`` axis) and its KV heads (``kv_heads_cache``,
+    with their query groups), as the sharding rules place them. Each slot's
+    queries attend only that slot's pages, and each KV head only its own
+    query group, so the partitioned call needs no communication.
     """
     from repro.kernels import ops as kernel_ops
 
     if live_blocks is not None:
         block_tables = block_tables[:, :live_blocks]
-    return kernel_ops.paged_attention(
-        q, pool["k"], pool["v"], block_tables, start,
-        k_scale=pool.get("k_scale"), v_scale=pool.get("v_scale"),
-        dequant_dtype=compute_dtype)
+    scales = tuple(pool[k] for k in ("k_scale", "v_scale") if k in pool)
+
+    def call(q, k, v, tables, start, *scales):
+        k_scale, v_scale = scales or (None, None)
+        return kernel_ops.paged_attention(
+            q, k, v, tables, start, k_scale=k_scale, v_scale=v_scale,
+            dequant_dtype=compute_dtype)
+
+    args = (q, pool["k"], pool["v"], block_tables, start) + scales
+    mesh, _ = active_context()
+    if mesh is None or mesh.size == 1:
+        return call(*args)
+    slots, heads = _drop_indivisible(
+        (q.shape[0], pool["k"].shape[2]),
+        constraint_spec(("batch", "kv_heads_cache"), mesh=mesh), mesh)
+    q_spec = P(slots, None, heads, None)
+    pool_spec = P(None, None, heads, None)
+    in_specs = (q_spec, pool_spec, pool_spec, P(slots, None), P(slots)) \
+        + (P(None, None, heads),) * len(scales)
+    return jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+                         out_specs=q_spec, check_vma=False)(*args)
 
 
 def attention_decode_paged(params: Params, x, pool: Params, block_tables,

@@ -14,7 +14,7 @@ makes that scheduling axis a first-class API:
   * ``backend`` selects the executing substrate per call site:
     ``"jnp"`` (pure-jnp reference paths), ``"pallas"`` (the TPU kernels in
     :mod:`repro.kernels`, interpret-mode on CPU) or ``"auto"`` (pallas iff
-    the default JAX backend is TPU).
+    the default JAX backend is TPU and no multi-device mesh is active).
   * Each strategy serializes to a canonical *spec string* —
     ``"serial?chunk=512"`` — parsed back by :func:`repro.moa.resolve`; the
     round trip ``resolve(spec).spec == spec`` holds for canonical specs.
@@ -34,13 +34,30 @@ from typing import Any, ClassVar, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.parallel import active_context
+
 __all__ = ["MOAStrategy", "BACKENDS", "resolved_backend"]
 
 BACKENDS = ("auto", "jnp", "pallas")
 
 
 def resolved_backend(backend: str) -> str:
-    """Map ``"auto"`` to the substrate the process is actually running on."""
+    """Map ``"auto"`` to the substrate the process is actually running on.
+
+    The Pallas kernels are single-device programs: GSPMD cannot partition
+    a Mosaic call by the operands' shardings. Under an active multi-device
+    sharding context (:func:`repro.parallel.activate`) ``"auto"`` therefore
+    resolves to the jnp paths, whose XLA ops partition with their operands,
+    and an explicit ``"pallas"`` is refused.
+    """
+    mesh, _ = active_context()
+    if mesh is not None and mesh.size > 1:
+        if backend == "pallas":
+            raise ValueError(
+                "the Pallas MOA kernels run on one device and cannot be "
+                f"partitioned over a {mesh.size}-device mesh; use backend "
+                "'jnp' or 'auto' under a mesh")
+        return "jnp"
     if backend != "auto":
         return backend
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
@@ -56,7 +73,8 @@ class MOAStrategy(abc.ABC):
 
     Attributes:
       backend: ``"auto"`` | ``"jnp"`` | ``"pallas"``. ``auto`` resolves to
-        the Pallas kernels on TPU and the jnp reference paths elsewhere.
+        the Pallas kernels on one TPU device and the jnp reference paths
+        elsewhere (on CPU, and under a multi-device mesh).
     """
 
     backend: str = "auto"

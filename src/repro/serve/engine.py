@@ -566,6 +566,7 @@ class ServeEngine:
         # the first run (tests drive the lifecycle methods directly)
         self._t_start = self._clock()
         self._compile_s = 0.0
+        self._on_logits = None
         self._log_start = 0
         self._spec_ticks = 0
         self._spec_emitted = 0
@@ -1273,6 +1274,8 @@ class ServeEngine:
             decode = self._decode
         logits, self.cache = decode(self.params, self.cache,
                                     jnp.asarray(toks))
+        if self._on_logits is not None:
+            self._on_logits(logits[:, -1])
         next_toks = np.asarray(self._sample(
             logits[:, -1], jnp.asarray(temps), jnp.asarray(greedy),
             self._next_key()))
@@ -1492,7 +1495,9 @@ class ServeEngine:
         self.params = params
 
     def start_run(self, *, warmup: bool = False,
-                  t_origin: Optional[float] = None) -> None:
+                  t_origin: Optional[float] = None,
+                  on_logits: Optional[Callable[[jax.Array], None]] = None
+                  ) -> None:
         """Reset per-run counters and start the engine clock.
 
         Part of the tick-level API (``start_run`` / ``tick`` /
@@ -1500,13 +1505,17 @@ class ServeEngine:
         router drives directly. ``t_origin`` pins the clock origin instead
         of reading the clock — the router passes one shared origin so every
         replica (including ones constructed mid-run on revival) reports on
-        the same fleet timeline.
+        the same fleet timeline. ``on_logits``, when given, is called with
+        each decode tick's ``(n_slots, vocab)`` last-position logits before
+        they are sampled (warmup ticks excluded).
         """
         self._compile_s = 0.0
+        self._on_logits = None
         if warmup:
             t0 = self._clock()
             self._warmup_tick()
             self._compile_s = self._clock() - t0
+        self._on_logits = on_logits
         # per-run counters: a reused engine (submit + repeated run) must not
         # carry stale fast-forward offsets, occupancy sums, or prior-run
         # admissions into its report
@@ -1581,7 +1590,8 @@ class ServeEngine:
                 self._decode_tick(results)
 
     def run(self, requests: Sequence[Request] = (),
-            max_steps: Optional[int] = None, *, warmup: bool = False
+            max_steps: Optional[int] = None, *, warmup: bool = False,
+            on_logits: Optional[Callable[[jax.Array], None]] = None
             ) -> Tuple[List[RequestResult], dict]:
         """Serve until every submitted request completes.
 
@@ -1597,8 +1607,10 @@ class ServeEngine:
         *before* the engine clock starts, so first-call XLA compilation
         lands in the report's ``compile_s`` instead of inflating
         ``wall_s`` / TTFT / ``tok_per_s`` (a warm engine pays ~0 here).
+        ``on_logits`` observes each decode tick's logits
+        (:meth:`start_run`).
         """
-        self.start_run(warmup=warmup)
+        self.start_run(warmup=warmup, on_logits=on_logits)
         for r in requests:
             self.submit(r)
         results: List[RequestResult] = []

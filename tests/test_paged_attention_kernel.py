@@ -337,3 +337,66 @@ def test_mesh_fused_parity():
     result = json.loads(out.stdout.strip().splitlines()[-1])
     for combo, ok in result["parity"].items():
         assert ok, f"{combo}: fused tokens diverged from gathered on mesh"
+
+
+_TP_SUBPROCESS_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import json, sys
+sys.path.insert(0, "src")
+import jax
+from repro.configs.registry import ARCHS, smoke_config
+from repro.launch.mesh import make_mesh
+from repro.models.api import build_model
+from repro.moa import resolve
+from repro.parallel import DEFAULT_RULES, activate
+from repro.serve import ServeEngine, poisson_workload
+
+cfg = smoke_config(ARCHS["zamba2-1.2b"])
+model = build_model(cfg)
+params = model.init(jax.random.PRNGKey(0))
+mesh = make_mesh((2, 4))
+out = {"kv_heads": cfg.n_kv_heads}
+runs = []
+for backend in ("jnp", "pallas"):
+    eng = ServeEngine(model, params, n_slots=2, max_len=32, paged=True,
+                      block_size=8, mesh=mesh, rules=DEFAULT_RULES,
+                      attn_backend=backend)
+    out["pool_spec"] = str(eng.cache["kv"]["k"].sharding.spec)
+    results, _ = eng.run(poisson_workload(
+        n_requests=4, vocab=cfg.vocab, rate_rps=100.0,
+        prompt_len_range=(4, 10), gen_len_range=(2, 6), seed=0), warmup=True)
+    runs.append([[int(t) for t in r.tokens] for r in results])
+out["parity"] = runs[0] == runs[1]
+with activate(mesh, DEFAULT_RULES):
+    out["moa_auto"] = resolve("serial?chunk=32").resolve_backend()
+    try:
+        resolve("serial?backend=pallas&chunk=32").resolve_backend()
+        out["moa_pallas_refused"] = False
+    except ValueError:
+        out["moa_pallas_refused"] = True
+print(json.dumps(out))
+"""
+
+
+@pytest.mark.slow
+def test_mesh_fused_parity_tensor_parallel():
+    """The fused kernel partitioned over a (data=2, model=4) host mesh with
+    the tensor-parallel rules: slots over ``data``, the hybrid's KV heads
+    over ``model`` (each device attends its own heads); greedy tokens match
+    the gathered backend. Under the mesh the MOA strategies resolve
+    ``auto`` to their jnp paths and refuse an explicit ``pallas``."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _TP_SUBPROCESS_SCRIPT],
+        capture_output=True, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        env=env, timeout=1200)
+    assert out.returncode == 0, out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["kv_heads"] % 4 == 0
+    assert "'model'" in result["pool_spec"], result["pool_spec"]
+    assert result["parity"], "fused tokens diverged from gathered on mesh"
+    assert result["moa_auto"] == "jnp"
+    assert result["moa_pallas_refused"]

@@ -139,15 +139,19 @@ def test_eos_early_exit(rng):
     engine = ServeEngine(model, params, n_slots=1, max_len=32,
                          clock=lambda: 0.0)
     full, _ = engine.run(_requests_from(toks, [6]))
-    eos = int(full[0].tokens[2])
+    gen = full[0].tokens
+    # the first token past the first that greedy decode has not emitted
+    # before: the request must stop exactly there, not at an earlier copy
+    stop = next(i for i in range(1, gen.size) if gen[i] not in gen[:i])
+    eos = int(gen[stop])
     engine2 = ServeEngine(model, params, n_slots=1, max_len=32,
                           clock=lambda: 0.0)
     results, _ = engine2.run([Request(
         uid=0, prompt=tuple(int(t) for t in toks[0]), max_new_tokens=6,
         eos_id=eos)])
     assert results[0].finish_reason is FinishReason.EOS
-    assert results[0].tokens.size == 3
-    np.testing.assert_array_equal(results[0].tokens, full[0].tokens[:3])
+    assert results[0].tokens.size == stop + 1
+    np.testing.assert_array_equal(results[0].tokens, gen[:stop + 1])
 
 
 # ---------------------------------------------------------------------------
@@ -245,6 +249,27 @@ def test_engine_rerun_resets_counters(rng):
     assert report["decode_steps"] == 3          # this run only (4 - 1 ticks)
     assert report["slot_occupancy"] <= 1.0
     assert report["slot_reuse"] == 0            # one admission this run
+
+
+def test_on_logits_observes_each_decode_tick(rng):
+    """``run(on_logits=...)`` sees every decode tick's last-position logits
+    before sampling (warmup excluded): each busy slot's greedy token is
+    their argmax. A later run without the callback does not call it."""
+    cfg, model, params = _built("llama3-8b", rng)
+    toks = np.asarray(jax.random.randint(rng, (2, 8), 0, cfg.vocab), np.int32)
+    engine = ServeEngine(model, params, n_slots=2, max_len=32,
+                         clock=lambda: 0.0)
+    seen = []
+    results, report = engine.run(
+        _requests_from(toks, [4, 4]), warmup=True,
+        on_logits=lambda lg: seen.append(np.asarray(lg)))
+    assert len(seen) == report["decode_steps"] == 3
+    assert all(lg.shape == (2, cfg.vocab) for lg in seen)
+    for r in results:
+        assert [int(np.argmax(lg[r.slot])) for lg in seen] \
+            == r.tokens[1:].tolist()
+    engine.run(_requests_from(toks, [4, 4]))
+    assert len(seen) == 3
 
 
 def test_padded_prefill_support_gates():

@@ -19,10 +19,11 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import parse_mesh
+from repro.launch.mesh import make_mesh, parse_mesh
 from repro.parallel import (DEFAULT_RULES, serve_cache_shardings,
                             serve_rules_for)
 
@@ -58,7 +59,7 @@ class TestServeRules:
 
 class TestCacheShardings:
     def _mesh(self):
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return make_mesh((1, 1))
 
     def test_dense_slot_layout(self):
         mesh = self._mesh()
@@ -131,6 +132,19 @@ class TestParseMesh:
     def test_rejects_bad_specs(self, bad):
         with pytest.raises(ValueError):
             parse_mesh(bad)
+
+
+def test_make_mesh_axes_accept_sharding_constraints():
+    """Meshes carry Auto axes: the model code places arrays with
+    ``with_sharding_constraint``, which refuses Explicit axes (the
+    default of ``jax.make_mesh``)."""
+    from jax.sharding import AxisType, NamedSharding
+
+    mesh = make_mesh((1, 1))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    x = jax.jit(lambda a: jax.lax.with_sharding_constraint(
+        a * 2, NamedSharding(mesh, P("data", "model"))))(jnp.ones((2, 2)))
+    assert float(x.sum()) == 8.0
 
 
 # ---------------------------------------------------------------------------

@@ -164,9 +164,11 @@ class TestCheckpoint:
         """Elastic restore path: device_put onto explicit shardings."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
+        from repro.launch.mesh import make_mesh
+
         m = CheckpointManager(str(tmp_path))
         m.save(0, self._tree(7))
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         template = jax.eval_shape(lambda: self._tree())
         sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), template)
         restored, _ = m.restore(template, shardings=sh)
@@ -255,3 +257,34 @@ class TestRuntime:
         d, m = plan_mesh_shape(n, model_parallel=16)
         assert d * m == n
         assert m <= 16
+
+
+# ---------------------------------------------------------------------------
+# persistent compile cache of the entry points
+# ---------------------------------------------------------------------------
+
+class TestCompileCache:
+    @pytest.fixture
+    def restore_cache_dir(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_repo_dir_when_unset(self, monkeypatch, restore_cache_dir):
+        from repro.launch.compile_cache import (REPO_CACHE_DIR,
+                                                enable_compile_cache)
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        assert REPO_CACHE_DIR.name == ".jax_cache"
+        assert (REPO_CACHE_DIR.parent / "src" / "repro").is_dir()
+
+    def test_environment_wins(self, monkeypatch, tmp_path,
+                              restore_cache_dir):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
