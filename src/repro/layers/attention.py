@@ -28,6 +28,7 @@ from repro.layers.numerics import NEG_INF, kv_scale_zeros, online_softmax_init
 from repro.layers.rope import apply_rope
 from repro.parallel import active_context, constrain
 from repro.parallel.sharding import _drop_indivisible, constraint_spec
+from repro.tracing import ATTENTION, layer_scope
 
 __all__ = [
     "init_attention", "attention_forward", "attention_decode",
@@ -79,6 +80,7 @@ def init_attention(rng, *, d_model: int, n_heads: int, n_kv_heads: int,
     return p
 
 
+@layer_scope(ATTENTION)
 def full_attention(q, k, v, *, causal: bool, positions_q=None, positions_kv=None,
                    kv_len=None):
     """One-shot attention (the spatial "adder tree"): materializes scores.
@@ -119,6 +121,7 @@ def full_attention(q, k, v, *, causal: bool, positions_q=None, positions_kv=None
     return o.reshape(B, Sq, H, D).astype(q.dtype)
 
 
+@layer_scope(ATTENTION)
 def flash_attention(q, k, v, *, causal: bool = True, q_chunk: int = 256,
                     kv_chunk: int = 512, kv_len=None):
     """Chunked-softmax attention (serialized MOA over the KV axis).
@@ -181,6 +184,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_chunk: int = 256,
     return o[:, :Sq].astype(q.dtype)
 
 
+@layer_scope(ATTENTION)
 def _moa_dot(x, w, *, strategy, compute_dtype):
     """Dense projection routed through the MOA engine (scope-aware).
 
@@ -194,6 +198,7 @@ def _moa_dot(x, w, *, strategy, compute_dtype):
                    compute_dtype=compute_dtype)
 
 
+@layer_scope(ATTENTION)
 def _project_qkv(params: Params, x, *, n_heads, n_kv_heads, head_dim,
                  compute_dtype, strategy=None):
     B, S, _ = x.shape
@@ -216,6 +221,7 @@ def _project_qkv(params: Params, x, *, n_heads, n_kv_heads, head_dim,
     return q, k, v
 
 
+@layer_scope(ATTENTION)
 def attention_forward(params: Params, x, *, positions, n_heads: int,
                       n_kv_heads: int, head_dim: int, causal: bool = True,
                       rope_theta: float = 10000.0, use_rope: bool = True,
@@ -320,6 +326,7 @@ def init_kv_pool(n_phys_blocks: int, block_size: int, n_kv_heads: int,
     return _constrain_pool(pool)
 
 
+@layer_scope(ATTENTION)
 def quantize_kv(x):
     """Per-(batch, pos, head) symmetric int8 quantization of K or V."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
@@ -333,6 +340,7 @@ def dequantize_kv(q, scale, dtype=jnp.bfloat16):
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
+@layer_scope(ATTENTION)
 def attention_decode(params: Params, x, cache: Params, pos, *, n_heads: int,
                      n_kv_heads: int, head_dim: int,
                      rope_theta: float = 10000.0, use_rope: bool = True,
@@ -403,6 +411,7 @@ def _verify_positions(pos, batch: int, n_tokens: int):
     return start.astype(jnp.int32)[:, None] + jnp.arange(n_tokens)[None, :]
 
 
+@layer_scope(ATTENTION)
 def attention_verify(params: Params, x, cache: Params, pos, *, n_heads: int,
                      n_kv_heads: int, head_dim: int,
                      rope_theta: float = 10000.0, use_rope: bool = True,
@@ -461,6 +470,7 @@ def attention_verify(params: Params, x, cache: Params, pos, *, n_heads: int,
     return y, new_cache
 
 
+@layer_scope(ATTENTION)
 def attention_verify_paged(params: Params, x, pool: Params, block_tables,
                            pos, *, n_heads: int, n_kv_heads: int,
                            head_dim: int, rope_theta: float = 10000.0,
@@ -626,6 +636,7 @@ def _paged_attention_fused(q, pool: Params, block_tables, start, *,
                          out_specs=q_spec, check_vma=False)(*args)
 
 
+@layer_scope(ATTENTION)
 def attention_decode_paged(params: Params, x, pool: Params, block_tables,
                            pos, *, n_heads: int, n_kv_heads: int,
                            head_dim: int, rope_theta: float = 10000.0,

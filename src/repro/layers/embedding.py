@@ -5,6 +5,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.layers.common import Params, truncated_normal_init
+from repro.tracing import EMBED, LOGITS, layer_scope
 
 __all__ = ["init_embedding", "embed", "unembed"]
 
@@ -21,12 +22,14 @@ def init_embedding(rng, vocab: int, d_model: int, *, tie: bool = True,
     return p
 
 
+@layer_scope(EMBED)
 def embed(params: Params, token_ids, *, compute_dtype=jnp.bfloat16):
     """Lookup: (B, S) int -> (B, S, d). A gather — the one-hot matmul MOA
     degenerate case (all-but-one operand zero; SCM removes them for free)."""
     return params["table"].astype(compute_dtype)[token_ids]
 
 
+@layer_scope(LOGITS)
 def unembed(params: Params, x, *, compute_dtype=jnp.bfloat16):
     """Logits: (B, S, d) -> (B, S, V). Vocab-dim output — shard over model
     axis and keep the softmax vocab-parallel (see losses.py)."""

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.layers.common import Params, dense_init
 from repro.layers.linear import project
 from repro.layers.numerics import silu_f32
+from repro.tracing import MLP, layer_scope
 
 __all__ = ["init_swiglu", "swiglu", "init_gelu_mlp", "gelu_mlp"]
 
@@ -28,6 +29,7 @@ def init_swiglu(rng, d_model: int, d_ff: int, dtype=jnp.float32) -> Params:
     }
 
 
+@layer_scope(MLP)
 def swiglu(params: Params, x, *, strategy=None,
            compute_dtype=jnp.bfloat16):
     g = project({"w": params["w_gate"]}, x, strategy=strategy,
@@ -49,6 +51,7 @@ def init_gelu_mlp(rng, d_model: int, d_ff: int, dtype=jnp.float32) -> Params:
     }
 
 
+@layer_scope(MLP)
 def gelu_mlp(params: Params, x, *, strategy=None,
              compute_dtype=jnp.bfloat16):
     h = project({"w": params["w_in"], "b": params["b_in"]}, x,

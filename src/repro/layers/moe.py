@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.layers.common import Params, dense_init
 from repro.layers.numerics import einsum_f32, silu_f32
 from repro.moa import active_strategy
+from repro.tracing import MOE, layer_scope
 
 __all__ = ["init_moe", "moe_forward"]
 
@@ -42,6 +43,7 @@ def init_moe(rng, *, d_model: int, d_ff: int, n_experts: int,
     }
 
 
+@layer_scope(MOE)
 def moe_forward(params: Params, x, *, n_experts: int, top_k: int,
                 capacity_factor: float = 1.25, group_size: int = 4096,
                 compute_dtype=jnp.bfloat16,

@@ -25,6 +25,7 @@ from repro.layers.numerics import (f32_upcast, silu_f32, softplus_f32,
                                    sum_f32)
 
 from repro.layers.common import Params, dense_init, init_rms_norm, rms_norm
+from repro.tracing import SSD, layer_scope
 
 __all__ = [
     "init_mamba2_block", "mamba2_forward", "mamba2_decode",
@@ -170,6 +171,7 @@ def _causal_depthwise_conv(x, w, b, hist=None):
     return y + b
 
 
+@layer_scope(SSD)
 def mamba2_forward(params: Params, x, *, d_state: int, headdim: int,
                    n_groups: int = 1, expand: int = 2, ssd_chunk: int = 256,
                    compute_dtype=jnp.bfloat16,
@@ -235,6 +237,7 @@ def init_ssm_state(batch: int, *, d_model: int, d_state: int, headdim: int,
     }
 
 
+@layer_scope(SSD)
 def mamba2_decode(params: Params, x, state, *, d_state: int, headdim: int,
                   n_groups: int = 1, expand: int = 2,
                   compute_dtype=jnp.bfloat16):

@@ -60,8 +60,10 @@ from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro import tracing
 from repro.launch.costing import request_decode_cost, spec_request_decode_cost
 from repro.layers.attention import resolve_attn_backend
 from repro.parallel import (activate, replicate_uneven_kv_heads,
@@ -703,7 +705,8 @@ class ServeEngine:
 
     def _build(self, name: str, fn, *, donate: Tuple[int, ...] = (),
                in_specs=None, out_specs=None, key_extra: tuple = ()):
-        """Jit ``fn`` through the module compile cache.
+        """Jit ``fn`` through the module compile cache, as the executable
+        ``jit_serve_<name>`` (:func:`repro.tracing.executable`).
 
         The key is ``(cfg, paged, mesh, rules, name, *key_extra)`` — two
         engines with the same model and cache layout share one jitted
@@ -722,7 +725,7 @@ class ServeEngine:
                     kwargs["in_shardings"] = in_specs
                 if out_specs is not None:
                     kwargs["out_shardings"] = out_specs
-            return jax.jit(fn, **kwargs)
+            return jax.jit(tracing.executable(name, fn), **kwargs)
 
         return self._ctx(_cached_jit(key, builder))
 
@@ -950,38 +953,43 @@ class ServeEngine:
         """Bind ``req`` to ``slot``: revive it if it was spilled by a
         preemption, start a chunked prefill if its prompt exceeds the
         chunk budget, else prefill in one shot and seed its first token."""
-        if req.uid in self._spilled:
-            self._revive(slot, req)
-            return
-        if self._chunk is not None and req.prompt_len > self._chunk:
-            self._begin_chunked(slot, req, now_s)
-            return
-        p = req.prompt_len
-        cached_tokens = 0
-        if self.paged:
-            logits, cached_tokens = self._paged_prefill(slot, req)
-        else:
-            prompt = req.prompt_array()
-            if self._padded:
-                bucket = self.scheduler.bucket_for(p)
-                toks = np.zeros((1, bucket), np.int32)
-                toks[0, :p] = prompt[0]
-                logits, pre = self._prefill(self.params, {"tokens": toks},
-                                            jnp.asarray(p, jnp.int32))
+        with TraceAnnotation(tracing.ADMIT, uid=req.uid,
+                             prompt_len=req.prompt_len):
+            if req.uid in self._spilled:
+                self._revive(slot, req)
+                return
+            if self._chunk is not None and req.prompt_len > self._chunk:
+                self._begin_chunked(slot, req, now_s)
+                return
+            p = req.prompt_len
+            cached_tokens = 0
+            if self.paged:
+                logits, cached_tokens = self._paged_prefill(slot, req)
             else:
-                logits, pre = self._prefill(self.params, {"tokens": prompt})
-            self.cache = self._write(self.cache, pre, slot)
-        if self.drafter is not None:
-            self.drafter.admit(slot, req.prompt)
-        self._seed(slot, req, logits, now_s, cached_tokens, 1, results)
+                prompt = req.prompt_array()
+                if self._padded:
+                    bucket = self.scheduler.bucket_for(p)
+                    toks = np.zeros((1, bucket), np.int32)
+                    toks[0, :p] = prompt[0]
+                    logits, pre = self._prefill(self.params, {"tokens": toks},
+                                                jnp.asarray(p, jnp.int32))
+                else:
+                    logits, pre = self._prefill(self.params,
+                                                {"tokens": prompt})
+                self.cache = self._write(self.cache, pre, slot)
+            if self.drafter is not None:
+                self.drafter.admit(slot, req.prompt)
+            self._seed(slot, req, logits, now_s, cached_tokens, 1, results)
 
     def _seed(self, slot: int, req: Request, logits, admitted_s: float,
               cached_tokens: int, chunks: int,
               results: List[RequestResult]) -> None:
         """Sample the first token from prefill logits and move the request
         into the decode set (or finish it on the spot)."""
-        first = int(np.asarray(req.sampler(
-            logits[:, -1], None if req.sampler.greedy else self._next_key()))[0])
+        sampled = req.sampler(
+            logits[:, -1], None if req.sampler.greedy else self._next_key())
+        with TraceAnnotation(tracing.PULL):
+            first = int(np.asarray(sampled)[0])
         t_first = self._now(self._t_start)
         metrics = RequestMetrics(arrival_s=req.arrival_s,
                                  admitted_s=admitted_s,
@@ -1260,43 +1268,48 @@ class ServeEngine:
 
     def _decode_tick(self, results: List[RequestResult]) -> None:
         """One batched decode step over all slots; advance active requests."""
-        toks = np.zeros((self.n_slots, 1), np.int32)
-        temps = np.zeros((self.n_slots,), np.float32)
-        greedy = np.ones((self.n_slots,), bool)
-        for slot, inf in self._inflight.items():
-            toks[slot, 0] = inf.next_token
-            temps[slot] = max(inf.request.sampler.temperature, 0.0)
-            greedy[slot] = inf.request.sampler.greedy
-        if self.paged:
-            hw = self._live_blocks(1)
-            decode = self._decode_for(hw)
-        else:
-            decode = self._decode
-        logits, self.cache = decode(self.params, self.cache,
-                                    jnp.asarray(toks))
-        if self._on_logits is not None:
-            self._on_logits(logits[:, -1])
-        next_toks = np.asarray(self._sample(
-            logits[:, -1], jnp.asarray(temps), jnp.asarray(greedy),
-            self._next_key()))
-        self._steps += 1
-        self._occupancy_sum += len(self._inflight) / self.n_slots
-        if self.paged:
-            self._block_occ_sum += self._pool.in_use / self.n_blocks
-            self._peak_blocks = max(self._peak_blocks, self._pool.in_use)
-            g, f = self._kv_bytes_tick(hw, 1)
-            self._gathered_kv_bytes += g
-            self._fused_kv_bytes += f
-            self._kv_step_log.append((g, f))
-        now = self._now(self._t_start)
-        for slot in sorted(self._inflight):
-            inf = self._inflight[slot]
-            tok = int(next_toks[slot])
-            inf.generated.append(tok)
-            inf.next_token = tok
-            if tok == inf.request.eos_id \
-                    or len(inf.generated) >= inf.request.max_new_tokens:
-                self._finish(inf, now, results)
+        with TraceAnnotation(tracing.DECODE):
+            toks = np.zeros((self.n_slots, 1), np.int32)
+            temps = np.zeros((self.n_slots,), np.float32)
+            greedy = np.ones((self.n_slots,), bool)
+            for slot, inf in self._inflight.items():
+                toks[slot, 0] = inf.next_token
+                temps[slot] = max(inf.request.sampler.temperature, 0.0)
+                greedy[slot] = inf.request.sampler.greedy
+            if self.paged:
+                hw = self._live_blocks(1)
+                decode = self._decode_for(hw)
+            else:
+                decode = self._decode
+            logits, self.cache = decode(self.params, self.cache,
+                                        jnp.asarray(toks))
+            if self._on_logits is not None:
+                self._on_logits(logits[:, -1])
+            sampled = self._sample(
+                logits[:, -1], jnp.asarray(temps), jnp.asarray(greedy),
+                self._next_key())
+        with TraceAnnotation(tracing.PULL):
+            next_toks = np.asarray(sampled)
+        with TraceAnnotation(tracing.COMMIT):
+            self._steps += 1
+            self._occupancy_sum += len(self._inflight) / self.n_slots
+            if self.paged:
+                self._block_occ_sum += self._pool.in_use / self.n_blocks
+                self._peak_blocks = max(self._peak_blocks,
+                                        self._pool.in_use)
+                g, f = self._kv_bytes_tick(hw, 1)
+                self._gathered_kv_bytes += g
+                self._fused_kv_bytes += f
+                self._kv_step_log.append((g, f))
+            now = self._now(self._t_start)
+            for slot in sorted(self._inflight):
+                inf = self._inflight[slot]
+                tok = int(next_toks[slot])
+                inf.generated.append(tok)
+                inf.next_token = tok
+                if tok == inf.request.eos_id \
+                        or len(inf.generated) >= inf.request.max_new_tokens:
+                    self._finish(inf, now, results)
 
     def _spec_tick(self, results: List[RequestResult]) -> None:
         """One speculative tick: draft → verify → accept → commit.
@@ -1311,63 +1324,68 @@ class ServeEngine:
         Each slot emits ``accepted + 1`` tokens, the last becoming its
         pending next token.
         """
-        k = self.spec_k
-        histories = {slot: tuple(inf.request.prompt) + tuple(inf.generated)
-                     for slot, inf in self._inflight.items()}
-        proposals = self.drafter.propose(histories)
-        toks = np.zeros((self.n_slots, k + 1), np.int32)
-        temps = np.zeros((self.n_slots,), np.float32)
-        greedy = np.ones((self.n_slots,), bool)
-        for slot, inf in self._inflight.items():
-            toks[slot, 0] = inf.next_token
-            toks[slot, 1:] = proposals[slot]
-            temps[slot] = max(inf.request.sampler.temperature, 0.0)
-            greedy[slot] = inf.request.sampler.greedy
-        if self.paged:
-            hw = self._live_blocks(k + 1)
-            verify = self._verify_for(hw)
-        else:
-            verify = self._verify
-        logits, self.cache, aux = verify(self.params, self.cache,
-                                         jnp.asarray(toks))
-        out, n_acc = self._accept(logits, jnp.asarray(toks[:, 1:]),
-                                  jnp.asarray(temps), jnp.asarray(greedy),
-                                  self._next_key())
-        out, n_acc = np.asarray(out), np.asarray(n_acc)
-        keep = np.zeros((self.n_slots,), np.int32)
-        for slot in self._inflight:
-            keep[slot] = n_acc[slot] + 1
-        self.cache = self._commit(self.cache, jnp.asarray(keep), aux)
-        self._steps += 1
-        self._spec_ticks += 1
-        self._occupancy_sum += len(self._inflight) / self.n_slots
-        self._spec_slot_steps += len(self._inflight)
-        if self.paged:
-            self._block_occ_sum += self._pool.in_use / self.n_blocks
-            self._peak_blocks = max(self._peak_blocks, self._pool.in_use)
-            g, f = self._kv_bytes_tick(hw, k + 1)
-            self._gathered_kv_bytes += g
-            self._fused_kv_bytes += f
-            self._kv_step_log.append((g, f))
-        now = self._now(self._t_start)
-        for slot in sorted(self._inflight):
-            inf = self._inflight[slot]
-            inf.tick_contexts.append(
-                inf.request.prompt_len + len(inf.generated) - 1)
-            accepted = int(n_acc[slot])
-            self._accept_hist[accepted] += 1
-            done = False
-            for tok in out[slot, : accepted + 1]:
-                tok = int(tok)
-                inf.generated.append(tok)
-                inf.next_token = tok
-                self._spec_emitted += 1
-                if tok == inf.request.eos_id \
-                        or len(inf.generated) >= inf.request.max_new_tokens:
-                    done = True
-                    break
-            if done:
-                self._finish(inf, now, results)
+        with TraceAnnotation(tracing.VERIFY):
+            k = self.spec_k
+            histories = {
+                slot: tuple(inf.request.prompt) + tuple(inf.generated)
+                for slot, inf in self._inflight.items()}
+            proposals = self.drafter.propose(histories)
+            toks = np.zeros((self.n_slots, k + 1), np.int32)
+            temps = np.zeros((self.n_slots,), np.float32)
+            greedy = np.ones((self.n_slots,), bool)
+            for slot, inf in self._inflight.items():
+                toks[slot, 0] = inf.next_token
+                toks[slot, 1:] = proposals[slot]
+                temps[slot] = max(inf.request.sampler.temperature, 0.0)
+                greedy[slot] = inf.request.sampler.greedy
+            if self.paged:
+                hw = self._live_blocks(k + 1)
+                verify = self._verify_for(hw)
+            else:
+                verify = self._verify
+            logits, self.cache, aux = verify(self.params, self.cache,
+                                             jnp.asarray(toks))
+            out, n_acc = self._accept(
+                logits, jnp.asarray(toks[:, 1:]), jnp.asarray(temps),
+                jnp.asarray(greedy), self._next_key())
+        with TraceAnnotation(tracing.PULL):
+            out, n_acc = np.asarray(out), np.asarray(n_acc)
+        with TraceAnnotation(tracing.COMMIT):
+            keep = np.zeros((self.n_slots,), np.int32)
+            for slot in self._inflight:
+                keep[slot] = n_acc[slot] + 1
+            self.cache = self._commit(self.cache, jnp.asarray(keep), aux)
+            self._steps += 1
+            self._spec_ticks += 1
+            self._occupancy_sum += len(self._inflight) / self.n_slots
+            self._spec_slot_steps += len(self._inflight)
+            if self.paged:
+                self._block_occ_sum += self._pool.in_use / self.n_blocks
+                self._peak_blocks = max(self._peak_blocks,
+                                        self._pool.in_use)
+                g, f = self._kv_bytes_tick(hw, k + 1)
+                self._gathered_kv_bytes += g
+                self._fused_kv_bytes += f
+                self._kv_step_log.append((g, f))
+            now = self._now(self._t_start)
+            for slot in sorted(self._inflight):
+                inf = self._inflight[slot]
+                inf.tick_contexts.append(
+                    inf.request.prompt_len + len(inf.generated) - 1)
+                accepted = int(n_acc[slot])
+                self._accept_hist[accepted] += 1
+                done = False
+                for tok in out[slot, : accepted + 1]:
+                    tok = int(tok)
+                    inf.generated.append(tok)
+                    inf.next_token = tok
+                    self._spec_emitted += 1
+                    if tok == inf.request.eos_id or len(inf.generated) \
+                            >= inf.request.max_new_tokens:
+                        done = True
+                        break
+                if done:
+                    self._finish(inf, now, results)
 
     # ---- warmup ------------------------------------------------------------
     def _warmup_tick(self) -> None:
@@ -1553,41 +1571,47 @@ class ServeEngine:
         may tick an idle replica safely)."""
         if self.scheduler.done:
             return
-        now = self._now(self._t_start)
-        if not self.scheduler.active and not self.scheduler.has_ready \
-                and self.scheduler.next_arrival_s > now:
-            # idle: fast-forward the engine clock to the next arrival
-            # (a gate-vetoed head sits in the ready queue, so has_ready
-            # guards against fast-forwarding past work that only needs
-            # blocks, not time)
-            self._fast_forward_s += self.scheduler.next_arrival_s - now
+        with TraceAnnotation(tracing.TICK):
             now = self._now(self._t_start)
-        if self.scheduling == "slo":
-            self._maybe_preempt(now)
-        gate = self._admission_gate if self.paged else None
-        while True:
-            # one at a time so each admission's block allocation is
-            # visible to the next gate evaluation
-            admitted = self.scheduler.admit_ready(now, gate=gate,
-                                                  limit=1)
-            if not admitted:
-                break
-            self._admit(admitted[0][0], admitted[0][1], now, results)
-        if self.paged and not self._inflight and not self._prefilling \
-                and self._spilled:
-            # stall escape: every runnable request is spilled but the
-            # gate vetoes the (fresh) ready head — revive a spilled one
-            # out of order; it holds its reservation, so it always fits
-            got = self.scheduler.admit_revivable(now, set(self._spilled))
-            if got is not None:
-                self._admit(got[0], got[1], now, results)
-        if self._prefilling:
-            self._prefill_tick(results)
-        if self._inflight:
-            if self.drafter is not None:
-                self._spec_tick(results)
-            else:
-                self._decode_tick(results)
+            if not self.scheduler.active and not self.scheduler.has_ready \
+                    and self.scheduler.next_arrival_s > now:
+                # idle: fast-forward the engine clock to the next arrival
+                # (a gate-vetoed head sits in the ready queue, so has_ready
+                # guards against fast-forwarding past work that only needs
+                # blocks, not time)
+                self._fast_forward_s += self.scheduler.next_arrival_s - now
+                now = self._now(self._t_start)
+            if self.scheduling == "slo":
+                with TraceAnnotation(tracing.SCHEDULE):
+                    self._maybe_preempt(now)
+            gate = self._admission_gate if self.paged else None
+            while True:
+                # one at a time so each admission's block allocation is
+                # visible to the next gate evaluation
+                with TraceAnnotation(tracing.SCHEDULE):
+                    admitted = self.scheduler.admit_ready(now, gate=gate,
+                                                          limit=1)
+                if not admitted:
+                    break
+                self._admit(admitted[0][0], admitted[0][1], now, results)
+            if self.paged and not self._inflight and not self._prefilling \
+                    and self._spilled:
+                # stall escape: every runnable request is spilled but the
+                # gate vetoes the (fresh) ready head — revive a spilled one
+                # out of order; it holds its reservation, so it always fits
+                with TraceAnnotation(tracing.SCHEDULE):
+                    got = self.scheduler.admit_revivable(now,
+                                                         set(self._spilled))
+                if got is not None:
+                    self._admit(got[0], got[1], now, results)
+            if self._prefilling:
+                with TraceAnnotation(tracing.PREFILL_CHUNK):
+                    self._prefill_tick(results)
+            if self._inflight:
+                if self.drafter is not None:
+                    self._spec_tick(results)
+                else:
+                    self._decode_tick(results)
 
     def run(self, requests: Sequence[Request] = (),
             max_steps: Optional[int] = None, *, warmup: bool = False,

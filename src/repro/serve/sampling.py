@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.layers.numerics import f32_upcast
+from repro.tracing import SAMPLE, layer_scope
 
 __all__ = ["Sampler", "GREEDY", "sample_batch"]
 
@@ -45,6 +46,7 @@ class Sampler:
 GREEDY = Sampler(0.0)
 
 
+@layer_scope(SAMPLE)
 def sample_batch(logits, temperature, greedy_mask, rng):
     """Per-row mixed sampling: ``logits (B, vocab)`` → ``(B,) int32``.
 

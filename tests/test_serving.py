@@ -390,3 +390,120 @@ def test_warmup_reports_compile_time(rng):
     assert report["compile_s"] > 0.0
     # the decode tick itself is milliseconds; compilation is not
     assert report["compile_s"] > report["wall_s"] / 10
+
+
+# ---------------------------------------------------------------------------
+# tracing: executable names, layer scopes, host spans (repro.tracing)
+# ---------------------------------------------------------------------------
+
+
+def _host_spans(trace_dir):
+    """``(name, start_ns, end_ns, args)`` of every ``serve.*`` host event
+    in the profile written under ``trace_dir``, by start time."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            spans.extend((ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+                         for ev in line.events
+                         if ev.name.startswith("serve."))
+    return sorted(spans, key=lambda s: s[1])
+
+
+def test_tick_phases_are_host_spans(rng, tmp_path):
+    """Under ``jax.profiler`` a decoding tick records ``serve.tick`` with
+    ``serve.decode``, ``serve.pull`` and ``serve.commit`` nested in that
+    order, and an admission records ``serve.admit`` (carrying the uid and
+    prompt length) with its first-token ``serve.pull`` inside."""
+    from repro import tracing
+
+    cfg, model, params = _built("mamba2-370m", rng)
+    toks = np.asarray(jax.random.randint(rng, (2, 6), 0, cfg.vocab),
+                      np.int32)
+    engine = ServeEngine(model, params, n_slots=2, max_len=32,
+                         clock=lambda: 0.0)
+    engine.run(_requests_from(toks, [3, 3]), warmup=True)    # compile
+    with jax.profiler.trace(str(tmp_path)):
+        engine.run(_requests_from(toks, [3, 3]))
+    spans = _host_spans(str(tmp_path))
+
+    def inside(outer):
+        return [s for s in spans if s is not outer
+                and outer[1] <= s[1] and s[2] <= outer[2]]
+
+    ticks = [s for s in spans if s[0] == tracing.TICK]
+    assert ticks
+    decoding = [t for t in ticks
+                if any(s[0] == tracing.DECODE for s in inside(t))]
+    assert decoding
+    for t in decoding:
+        phases = [s[0] for s in inside(t) if s[0] in (
+            tracing.DECODE, tracing.PULL, tracing.COMMIT)]
+        assert phases[-3:] == [tracing.DECODE, tracing.PULL, tracing.COMMIT]
+    admits = [s for s in spans if s[0] == tracing.ADMIT]
+    assert sorted(int(a[3]["uid"]) for a in admits) == [0, 1]
+    assert {int(a[3]["prompt_len"]) for a in admits} == {6}
+    for a in admits:
+        assert [s[0] for s in inside(a)].count(tracing.PULL) == 1
+    # every span sits inside a tick
+    assert all(any(t[1] <= s[1] and s[2] <= t[2] for t in ticks)
+               for s in spans)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_engine_callables_are_named_executables(rng, paged):
+    """Every callable the engine jits lowers to the module
+    ``jit_serve_<name>``: every live-block bucket of decode shares
+    ``jit_serve_decode``."""
+    cfg, model, params = _built("zamba2-1.2b" if paged else "mamba2-370m",
+                                rng)
+    kw = dict(paged=True, block_size=8) if paged else {}
+    engine = ServeEngine(model, params, n_slots=2, max_len=32, **kw)
+    toks = jnp.zeros((2, 1), jnp.int32)
+    decodes = [engine._decode_for(hw) for hw in engine._hw_buckets()] \
+        if paged else [engine._decode]
+    for decode in decodes:
+        text = decode.lower(engine.params, engine.cache, toks).as_text()
+        assert "module @jit_serve_decode" in text
+    logits = jnp.zeros((2, cfg.vocab), jnp.float32)
+    text = engine._sample.lower(logits, jnp.zeros((2,)), jnp.ones((2,), bool),
+                                jax.random.PRNGKey(0)).as_text()
+    assert "module @jit_serve_sample" in text
+
+
+@pytest.mark.parametrize("arch,scopes", [
+    ("zamba2-1.2b", {"embed", "ssd", "attention", "mlp", "logits"}),
+    ("mamba2-370m", {"embed", "ssd", "logits"})])
+def test_decode_ops_carry_layer_scopes(rng, arch, scopes):
+    """The compiled decode step's op metadata names the layer of each op:
+    the family's layers all appear, and the scan plumbing (the stacking of
+    per-layer state and of the KV pool) sits in no layer."""
+    import re
+
+    from repro import tracing
+
+    cfg, model, params = _built(arch, rng)
+    paged = arch == "zamba2-1.2b"
+    kw = dict(paged=True, block_size=8) if paged else {}
+    engine = ServeEngine(model, params, n_slots=2, max_len=32, **kw)
+    decode = engine._decode_for(1) if paged else engine._decode
+    hlo = decode.lower(engine.params, engine.cache,
+                       jnp.zeros((2, 1), jnp.int32)).compile().as_text()
+    names = re.findall(r'op_name="(jit\(serve_decode\)/[^"]*)"', hlo)
+
+    def layer(name):
+        return next((p for p in name.split("/")
+                     if p in tracing.LAYER_SCOPES), "")
+
+    assert {layer(n) for n in names} - {""} == scopes
+    plumbing = [n for n in names
+                if n.endswith("while/body/dynamic_update_slice")]
+    assert plumbing and not any(layer(n) for n in plumbing)
