@@ -274,17 +274,18 @@ def _constrain_cache(cache: Params) -> Params:
     return out
 
 
-def _constrain_pool(pool: Params) -> Params:
+def _constrain_pool(pool: Params, *, stacked: bool = False) -> Params:
     """Paged twin of :func:`_constrain_cache`: the physical block axis is
     shared across slots (replicated — block tables are logical), only the
-    head dimension shards."""
+    head dimension shards. ``stacked`` pools carry a leading (replicated)
+    application-point axis."""
+    lead = (None,) * (3 if stacked else 2)
     out = dict(pool)
     for key in ("k", "v"):
-        out[key] = constrain(out[key], None, None,
-                             "kv_heads_cache", "head_dim")
+        out[key] = constrain(out[key], *lead, "kv_heads_cache", "head_dim")
     for key in ("k_scale", "v_scale"):
         if key in out:
-            out[key] = constrain(out[key], None, None, "kv_heads_cache")
+            out[key] = constrain(out[key], *lead, "kv_heads_cache")
     return out
 
 
@@ -643,7 +644,7 @@ def attention_decode_paged(params: Params, x, pool: Params, block_tables,
                            use_rope: bool = True,
                            compute_dtype=jnp.bfloat16,
                            strategy=None, backend: str = "jnp",
-                           live_blocks: Optional[int] = None,
+                           live_blocks: Optional[int] = None, app=None,
                            ) -> Tuple[jax.Array, Params]:
     """One decode step against a *paged* KV pool.
 
@@ -662,9 +663,15 @@ def attention_decode_paged(params: Params, x, pool: Params, block_tables,
     tokens are bit-identical, floats agree to online-softmax reassociation.
     ``live_blocks`` (static) bounds both paths to the batch's high-water
     logical block.
+
+    With ``app`` (a traced application-point index) ``pool`` is a stack of
+    pools, leaves ``(n_apps, n_phys_blocks, block_size, ...)``: the new
+    K/V is written into the stack at ``(app, block, offset)`` — in place
+    when the stack is a donated loop carry — the score reduction reads
+    pool ``app``, and the whole stack is returned.
     """
     B = x.shape[0]
-    bs = pool["k"].shape[1]
+    bs = pool["k"].shape[-3]
     q, k_new, v_new = _project_qkv(
         params, x, n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim,
         compute_dtype=compute_dtype, strategy=strategy)
@@ -677,27 +684,33 @@ def attention_decode_paged(params: Params, x, pool: Params, block_tables,
     blk = block_tables[jnp.arange(B), cur // bs]
     off = cur % bs
 
+    at = (blk, off) if app is None else (app, blk, off)
+
+    def write(leaf, new):
+        return leaf.at[at].set(new[:, 0].astype(leaf.dtype))
+
     new_pool = dict(pool)
     if "k_scale" in pool:
         kq, ks = quantize_kv(k_new)
         vq, vs = quantize_kv(v_new)
-        new_pool["k"] = pool["k"].at[blk, off].set(kq[:, 0])
-        new_pool["v"] = pool["v"].at[blk, off].set(vq[:, 0])
-        new_pool["k_scale"] = pool["k_scale"].at[blk, off].set(ks[:, 0])
-        new_pool["v_scale"] = pool["v_scale"].at[blk, off].set(vs[:, 0])
+        new_pool["k"] = write(pool["k"], kq)
+        new_pool["v"] = write(pool["v"], vq)
+        new_pool["k_scale"] = write(pool["k_scale"], ks)
+        new_pool["v_scale"] = write(pool["v_scale"], vs)
     else:
-        new_pool["k"] = pool["k"].at[blk, off].set(
-            k_new[:, 0].astype(pool["k"].dtype))
-        new_pool["v"] = pool["v"].at[blk, off].set(
-            v_new[:, 0].astype(pool["v"].dtype))
-    new_pool = _constrain_pool(new_pool)
+        new_pool["k"] = write(pool["k"], k_new)
+        new_pool["v"] = write(pool["v"], v_new)
+    new_pool = _constrain_pool(new_pool, stacked=app is not None)
+    view = new_pool if app is None else _constrain_pool(jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, app, keepdims=False),
+        new_pool))
 
     if resolve_attn_backend(backend) == "pallas":
-        o = _paged_attention_fused(q, new_pool, block_tables, cur,
+        o = _paged_attention_fused(q, view, block_tables, cur,
                                    compute_dtype=compute_dtype,
                                    live_blocks=live_blocks)
     else:
-        k_cache, v_cache = gather_paged_kv(new_pool, block_tables,
+        k_cache, v_cache = gather_paged_kv(view, block_tables,
                                            compute_dtype,
                                            live_blocks=live_blocks)
         o = full_attention(q, k_cache, v_cache, causal=False, kv_len=cur + 1)

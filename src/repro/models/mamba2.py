@@ -167,20 +167,38 @@ def prefill_chunk(params: Params, batch: dict, cfg: ModelConfig, *,
              "pos": state["pos"] + jnp.asarray(S, jnp.int32)})
 
 
+def _index(tree: Params, i) -> Params:
+    """Entry ``i`` of every leaf of a stacked ``(L, ...)`` tree, read in
+    place (a dynamic index, not a slice of the stack)."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+
+def _decode_layer(layer: Params, states: Params, i, h, *,
+                  cfg: ModelConfig):
+    """Decode layer ``i`` with its weights ``layer``: read its state from
+    the carried ``(L, ...)`` state by index and write the new state back
+    there, which a donated cache updates in place. Returns ``(h, states)``.
+    """
+    hn = rms_norm(layer["norm"], h)
+    y, new_state = mamba2_decode(
+        layer["mixer"], hn, _index(states, i), d_state=cfg.d_state,
+        headdim=cfg.headdim, n_groups=cfg.n_groups, expand=cfg.expand,
+        compute_dtype=cfg.cdtype)
+    states = jax.tree.map(
+        lambda a, s: lax.dynamic_update_index_in_dim(a, s, i, 0),
+        states, new_state)
+    return h + constrain(y, "batch", None, "embed"), states
+
+
 def decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig):
     h = embed(params["embed"], tokens, compute_dtype=cfg.cdtype)
     h = constrain(h, "batch", None, "embed")
-
-    def body(carry, xs):
-        layer, state = xs
-        hn = rms_norm(layer["norm"], carry)
-        y, new_state = mamba2_decode(
-            layer["mixer"], hn, state, d_state=cfg.d_state,
-            headdim=cfg.headdim, n_groups=cfg.n_groups, expand=cfg.expand,
-            compute_dtype=cfg.cdtype)
-        return carry + constrain(y, "batch", None, "embed"), new_state
-
-    h, new_layers = lax.scan(body, h, (params["layers"], cache["layers"]))
+    h, new_layers = lax.fori_loop(
+        0, cfg.n_layers,
+        lambda i, c: _decode_layer(_index(params["layers"], i), c[1], i, c[0],
+                                   cfg=cfg),
+        (h, cache["layers"]))
     h = rms_norm(params["final_norm"], h)
     logits = unembed(params["embed"], h, compute_dtype=cfg.cdtype)
     return (constrain(logits, "batch", None, "vocab"),

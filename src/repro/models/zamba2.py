@@ -26,8 +26,7 @@ from repro.layers import attention as attn_lib
 from repro.layers.common import Params, init_rms_norm, rms_norm
 from repro.layers.embedding import embed, init_embedding, unembed
 from repro.layers.mlp import init_swiglu, swiglu
-from repro.layers.ssd import (init_mamba2_block, init_ssm_state,
-                              mamba2_decode, mamba2_forward)
+from repro.layers.ssd import init_ssm_state
 from repro.models import mamba2 as mamba_lm
 from repro.models import transformer as dense
 from repro.models import verify_common
@@ -354,57 +353,83 @@ def prefill_chunk(params: Params, batch: dict, cfg: ModelConfig, *,
     return constrain(logits, "batch", None, "vocab"), cache
 
 
+def _decode_layers(params: Params, cache: Params, h, *, cfg: ModelConfig,
+                   attend):
+    """The decode step's layer loop, shared by :func:`decode_step` and
+    :func:`paged_decode_step`: for each application point ``g``, Mamba-2
+    layers ``g*attn_every .. (g+1)*attn_every - 1`` then the shared block,
+    then the ``n_layers % attn_every`` tail layers.
+
+    Weights are read from the stacked ``(L, ...)`` parameters by layer
+    index; the SSM state ``cache["ssm"]`` and the KV stack ``cache["kv"]``
+    ride whole in the loop carry and each layer writes its new state back
+    by index, so a donated cache is updated in place: nothing is sliced
+    into groups or restacked. ``attend(hn, kv, g) -> (a, kv)`` is the
+    shared block's attention at application ``g`` over the carried stack.
+    Every loop has a static trip count. The tail loop indexes a slice of
+    just its own layers' weights: XLA hoists a loop's f32->bf16 weight
+    cast out of it over the whole array the loop indexes, so indexing the
+    full stack there would cast all ``n_layers`` a second time. Returns
+    ``(h, ssm, kv)``.
+    """
+    n_apps, per_group, tail = _grouped(cfg)
+    n_head = n_apps * per_group
+
+    def mamba_layers(weights, w0, i0):
+        """Loop body: step ``j`` runs layer ``i0 + j``, weights
+        ``weights[w0 + j]``."""
+        def body(j, carry):
+            h, ssm, kv = carry
+            h, ssm = mamba_lm._decode_layer(
+                mamba_lm._index(weights, w0 + j), ssm, i0 + j, h, cfg=cfg)
+            return h, ssm, kv
+        return body
+
+    def group(g, carry):
+        first = g * per_group
+        h, ssm, kv = lax.fori_loop(
+            0, per_group, mamba_layers(params["layers"], first, first), carry)
+        app_norm = mamba_lm._index(params["app_norms"], g)
+        hn = rms_norm(app_norm["attn"], h)
+        a, kv = attend(hn, kv, g)
+        h = h + constrain(a, "batch", None, "embed")
+        hn = rms_norm(app_norm["mlp"], h)
+        m = swiglu(params["shared_mlp"], hn, strategy=cfg.moa_for("mlp"),
+                   compute_dtype=cfg.cdtype)
+        return h + constrain(m, "batch", None, "embed"), ssm, kv
+
+    carry = lax.fori_loop(0, n_apps, group, (h, cache["ssm"], cache["kv"]))
+    if tail:
+        tail_layers = jax.tree.map(lambda a: a[n_head:], params["layers"])
+        carry = lax.fori_loop(0, tail, mamba_layers(tail_layers, 0, n_head),
+                              carry)
+    return carry
+
+
+def _decode_logits(params: Params, h, cfg: ModelConfig):
+    h = rms_norm(params["final_norm"], h)
+    logits = unembed(params["embed"], h, compute_dtype=cfg.cdtype)
+    return constrain(logits, "batch", None, "vocab")
+
+
 def decode_step(params: Params, cache: Params, tokens, cfg: ModelConfig):
     pos = cache["pos"]
     h = embed(params["embed"], tokens, compute_dtype=cfg.cdtype)
     h = constrain(h, "batch", None, "embed")
-    n_apps, per_group, tail = _grouped(cfg)
-    head_states = jax.tree.map(
-        lambda a: a[: n_apps * per_group].reshape(
-            (n_apps, per_group) + a.shape[1:]), cache["ssm"])
-    tail_states = jax.tree.map(lambda a: a[n_apps * per_group:],
-                               cache["ssm"]) if tail else None
-    head, tail_p = _split_layers(params, cfg)
 
-    def mamba_body(carry, xs):
-        layer, state = xs
-        hn = rms_norm(layer["norm"], carry)
-        y, new_state = mamba2_decode(
-            layer["mixer"], hn, state, d_state=cfg.d_state,
-            headdim=cfg.headdim, n_groups=cfg.n_groups, expand=cfg.expand,
-            compute_dtype=cfg.cdtype)
-        return carry + constrain(y, "batch", None, "embed"), new_state
+    def attend(hn, kv, g):
+        a, kv_g = attn_lib.attention_decode(
+            params["shared_attn"], hn, mamba_lm._index(kv, g), pos,
+            n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+            head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
+            compute_dtype=cfg.cdtype, strategy=cfg.moa_for("attention"))
+        return a, jax.tree.map(
+            lambda a, n: lax.dynamic_update_index_in_dim(a, n, g, 0),
+            kv, kv_g)
 
-    def group_body(carry, xs):
-        group_layers, group_states, app_norm, kv = xs
-        out, new_states = lax.scan(mamba_body, carry,
-                                   (group_layers, group_states))
-        hn = rms_norm(app_norm["attn"], out)
-        a, new_kv = attn_lib.attention_decode(
-            params["shared_attn"], hn, kv, pos, n_heads=cfg.n_heads,
-            n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
-            rope_theta=cfg.rope_theta, compute_dtype=cfg.cdtype,
-            strategy=cfg.moa_for("attention"))
-        out = out + constrain(a, "batch", None, "embed")
-        hn = rms_norm(app_norm["mlp"], out)
-        m = swiglu(params["shared_mlp"], hn, strategy=cfg.moa_for("mlp"),
-                   compute_dtype=cfg.cdtype)
-        out = out + constrain(m, "batch", None, "embed")
-        return out, (new_states, new_kv)
-
-    h, (new_head_states, new_kv) = lax.scan(
-        group_body, h,
-        (head, head_states, params["app_norms"], cache["kv"]))
-    new_ssm = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]),
-                           new_head_states)
-    if tail_states is not None:
-        h, new_tail = lax.scan(mamba_body, h, (tail_p, tail_states))
-        new_ssm = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0),
-                               new_ssm, new_tail)
-    h = rms_norm(params["final_norm"], h)
-    logits = unembed(params["embed"], h, compute_dtype=cfg.cdtype)
-    return (constrain(logits, "batch", None, "vocab"),
-            {"ssm": new_ssm, "kv": new_kv, "pos": pos + 1})
+    h, ssm, kv = _decode_layers(params, cache, h, cfg=cfg, attend=attend)
+    return (_decode_logits(params, h, cfg),
+            {"ssm": ssm, "kv": kv, "pos": pos + 1})
 
 
 def paged_decode_step(params: Params, cache: Params, tokens,
@@ -412,60 +437,24 @@ def paged_decode_step(params: Params, cache: Params, tokens,
     """Paged decode step: identical to :func:`decode_step` except the
     shared attention block reads/writes its KV through per-slot block
     tables (bounded to ``live_blocks``, dispatched per
-    ``cfg.attn_backend``); the dense per-slot SSM recurrence is
-    untouched."""
+    ``cfg.attn_backend``); the new token's K/V goes straight into the
+    stacked pool at ``(application, block, offset)``. The dense per-slot
+    SSM recurrence is untouched."""
     pos, tables = cache["pos"], cache["block_tables"]
     h = embed(params["embed"], tokens, compute_dtype=cfg.cdtype)
     h = constrain(h, "batch", None, "embed")
-    n_apps, per_group, tail = _grouped(cfg)
-    head_states = jax.tree.map(
-        lambda a: a[: n_apps * per_group].reshape(
-            (n_apps, per_group) + a.shape[1:]), cache["ssm"])
-    tail_states = jax.tree.map(lambda a: a[n_apps * per_group:],
-                               cache["ssm"]) if tail else None
-    head, tail_p = _split_layers(params, cfg)
 
-    def mamba_body(carry, xs):
-        layer, state = xs
-        hn = rms_norm(layer["norm"], carry)
-        y, new_state = mamba2_decode(
-            layer["mixer"], hn, state, d_state=cfg.d_state,
-            headdim=cfg.headdim, n_groups=cfg.n_groups, expand=cfg.expand,
-            compute_dtype=cfg.cdtype)
-        return carry + constrain(y, "batch", None, "embed"), new_state
-
-    def group_body(carry, xs):
-        group_layers, group_states, app_norm, kv_pool = xs
-        out, new_states = lax.scan(mamba_body, carry,
-                                   (group_layers, group_states))
-        hn = rms_norm(app_norm["attn"], out)
-        a, new_pool = attn_lib.attention_decode_paged(
-            params["shared_attn"], hn, kv_pool, tables, pos,
+    def attend(hn, pools, g):
+        return attn_lib.attention_decode_paged(
+            params["shared_attn"], hn, pools, tables, pos,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
             compute_dtype=cfg.cdtype, strategy=cfg.moa_for("attention"),
-            backend=cfg.attn_backend, live_blocks=live_blocks)
-        out = out + constrain(a, "batch", None, "embed")
-        hn = rms_norm(app_norm["mlp"], out)
-        m = swiglu(params["shared_mlp"], hn, strategy=cfg.moa_for("mlp"),
-                   compute_dtype=cfg.cdtype)
-        out = out + constrain(m, "batch", None, "embed")
-        return out, (new_states, new_pool)
+            backend=cfg.attn_backend, live_blocks=live_blocks, app=g)
 
-    h, (new_head_states, new_kv) = lax.scan(
-        group_body, h,
-        (head, head_states, params["app_norms"], cache["kv"]))
-    new_ssm = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]),
-                           new_head_states)
-    if tail_states is not None:
-        h, new_tail = lax.scan(mamba_body, h, (tail_p, tail_states))
-        new_ssm = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0),
-                               new_ssm, new_tail)
-    h = rms_norm(params["final_norm"], h)
-    logits = unembed(params["embed"], h, compute_dtype=cfg.cdtype)
-    return (constrain(logits, "batch", None, "vocab"),
-            {"ssm": new_ssm, "kv": new_kv, "block_tables": tables,
-             "pos": pos + 1})
+    h, ssm, kv = _decode_layers(params, cache, h, cfg=cfg, attend=attend)
+    return (_decode_logits(params, h, cfg),
+            {"ssm": ssm, "kv": kv, "block_tables": tables, "pos": pos + 1})
 
 
 # ---------------------------------------------------------------------------

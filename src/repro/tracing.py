@@ -7,8 +7,8 @@ nothing else; with no trace running none of them records or costs anything:
   each layer kind, set where the layer's math is (:func:`layer_scope`). They
   land in the HLO op metadata (``op_name``, the trace's ``tf_op``) as a path
   component, e.g. ``jit(serve_decode)/while/body/ssd/dot_general``. Work
-  outside every layer (the model's scans slicing and stacking per-layer
-  state and the KV pool, norms, residual adds) carries none.
+  outside every layer (the layer loops' indexed reads and writes of
+  weights, state and the KV pool, norms, residual adds) carries none.
 * **executables** — every callable the serve engine jits is named
   ``serve_<name>`` (:func:`executable`), so its XLA module reads
   ``jit_serve_<name>``: ``jit_serve_decode`` (every live-block bucket),

@@ -484,8 +484,9 @@ def test_engine_callables_are_named_executables(rng, paged):
     ("mamba2-370m", {"embed", "ssd", "logits"})])
 def test_decode_ops_carry_layer_scopes(rng, arch, scopes):
     """The compiled decode step's op metadata names the layer of each op:
-    the family's layers all appear, and the scan plumbing (the stacking of
-    per-layer state and of the KV pool) sits in no layer."""
+    the family's layers all appear, and the layer loop's plumbing (the
+    in-place write of each layer's recurrent state into the carried
+    stack) sits in no layer."""
     import re
 
     from repro import tracing
@@ -504,6 +505,6 @@ def test_decode_ops_carry_layer_scopes(rng, arch, scopes):
                      if p in tracing.LAYER_SCOPES), "")
 
     assert {layer(n) for n in names} - {""} == scopes
-    plumbing = [n for n in names
-                if n.endswith("while/body/dynamic_update_slice")]
+    plumbing = [n for n in names if "/while/body/" in n
+                and n.endswith("/dynamic_update_slice")]
     assert plumbing and not any(layer(n) for n in plumbing)
